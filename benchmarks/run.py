@@ -29,7 +29,11 @@ def main() -> None:
                          "artifact (schema: benchmarks/common.write_json)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+
     from . import paper_figs as pf
+
+    use_compile_cache()
 
     benches = [
         ("speedup_vs_states", pf.bench_speedup_vs_states),   # Fig 10 + 15

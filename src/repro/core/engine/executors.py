@@ -202,20 +202,20 @@ class LaneExecutor:
         self.lowering_kinds.clear()
 
     def _jit_lowering(self, body):
-        """jit a lowering body under the retrace counter and buffer donation.
+        """jit a lowering body under the retrace counter.
 
         ``body`` takes the plan's runtime operands positionally —
         ``(bytes_buf, lengths[, entry[, entry_classes]])`` per
         ``plan.entry`` — which is exactly how ``run`` calls the compiled
-        program, so one wrapper serves every entry mode.
+        program, so one wrapper serves every entry mode.  Nothing is
+        donated: no output has the byte buffer's shape and dtype, so XLA
+        could alias none of it.
         """
-        donate = (0,) if jax.default_backend() != "cpu" else ()
-
         def impl(*args):
             self.traces += 1  # side effect fires at trace time only
             return body(*args)
 
-        return jax.jit(impl, donate_argnums=donate)
+        return jax.jit(impl)
 
     def _lower(self, plan: LanePlan, layout, batch: int):
         """Backend hook: build the compiled program of one plan."""
@@ -459,12 +459,12 @@ class LocalExecutor(LaneExecutor):
 
     The speculative lowering fuses classification residue, uniform chunking,
     candidate gather, chunk matching, and the Eq. 8 merge in one jitted call
-    per bucket (donated input buffer on accelerators); only the [B, K]
-    final-state array crosses back to the host.  With ``use_kernel=True``
-    every spec plan — exact-entry *and* ``ENTRY_LANES`` — dispatches a fused
-    Pallas kernel behind an all-absorbed bucket early exit, and the kernel
-    itself skips symbol blocks past the point a document's lanes all absorb
-    (the in-kernel early exit; per-document skipped-block counts drain via
+    per bucket; only the [B, K] final-state array crosses back to the host.
+    With ``use_kernel=True`` every spec plan — exact-entry *and*
+    ``ENTRY_LANES`` — dispatches a fused Pallas kernel behind an
+    all-absorbed bucket early exit, and the kernel itself skips symbol
+    blocks past the point a document's lanes all absorb (the in-kernel early
+    exit; per-document skipped-block counts drain via
     ``kernel_skipped_steps()``).
     """
 

@@ -7,7 +7,7 @@ executor is the device-mesh lowering of the one ``LanePlan`` (see
 ``engine.executors``), on a 2-D ``("doc", "chunk")`` mesh
 (``launch.mesh.make_matcher_mesh``):
 
-  * the **chunk axis is sharded over "chunk"** (``jax_compat.shard_map``):
+  * the **chunk axis is sharded over "chunk"** (``jax.shard_map``):
     each device matches its contiguous run of chunks x candidate lanes
     locally;
   * the **document axis is sharded over "doc"**: mesh row ``r`` owns tile
@@ -84,7 +84,14 @@ class ShardedExecutor(LaneExecutor):
         from ...launch.mesh import make_matcher_mesh, matcher_mesh_extents
         if mesh is None:
             mesh = make_matcher_mesh()
-        self.mesh = mesh
+        # the lowering places every operand itself (shard_map specs; the
+        # tables are program constants, replicated by the compiler), so it
+        # runs on Auto axes whatever axis types the caller's mesh carries:
+        # Explicit axes (jax.make_mesh's default) refuse both the table
+        # closure and the out[0] slice
+        self.mesh = jax.sharding.Mesh(
+            mesh.devices, mesh.axis_names,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names))
         self.doc_shards, self.chunk_shards = matcher_mesh_extents(mesh)
         self.chunk_axis = "chunk" if "chunk" in mesh.axis_names else "data"
         self.devices = self.doc_shards * self.chunk_shards
@@ -117,24 +124,6 @@ class ShardedExecutor(LaneExecutor):
         self.lowering_kinds[self._plan_key(plan, batch)] = "spec-sharded"
         return self._lower_spec_sharded(plan, layout)
 
-    def _replicated_tables(self):
-        """Pin the constant matcher tables onto every mesh device up front
-        (distributed.sharding.matcher_table_specs), instead of relying on
-        implicit transfer at first dispatch."""
-        from jax.sharding import NamedSharding
-
-        from ...distributed.sharding import matcher_table_specs
-
-        t = self.t
-        specs = matcher_table_specs(self.mesh)
-
-        def repl(name, arr):
-            return jax.device_put(arr, NamedSharding(self.mesh, specs[name]))
-
-        return (repl("table_pad", t.table_pad_j),
-                repl("cand_pad", t.cand_pad_j),
-                repl("cidx_pad", t.cidx_pad_j))
-
     # -- sequential plan: document axis over both mesh axes ------------------
 
     def _lower_seq_sharded(self, plan: LanePlan, batch: int):
@@ -146,7 +135,7 @@ class ShardedExecutor(LaneExecutor):
         from jax.sharding import PartitionSpec as P
 
         from ...distributed.sharding import doc_batch_spec
-        from ...jax_compat import shard_map
+        from jax import shard_map
 
         row_ax = tuple(doc_batch_spec(self.mesh, batch))
         buf_spec, len_spec = P(*row_ax, None), P(*row_ax)
@@ -185,7 +174,7 @@ class ShardedExecutor(LaneExecutor):
         plan)."""
         from ...distributed.sharding import (matcher_chunk_specs,
                                              matcher_lane_specs)
-        from ...jax_compat import shard_map
+        from jax import shard_map
 
         t = self.t
         rows = self._layout_rows(layout)
@@ -200,7 +189,8 @@ class ShardedExecutor(LaneExecutor):
             in_specs, out_spec = matcher_lane_specs(self.mesh)
         else:
             in_specs, out_spec = matcher_chunk_specs(self.mesh)
-        table_pad, cand_pad, cidx_pad = self._replicated_tables()
+        table_pad, cand_pad, cidx_pad = (t.table_pad_j, t.cand_pad_j,
+                                         t.cidx_pad_j)
 
         def scan_chunks(chunk_loc, init):
             """Per-device chunk-scan stage over this shard's lanes."""
@@ -280,32 +270,28 @@ class ShardedExecutor(LaneExecutor):
             # predecessor of exact chunks both point at it
             cls_pad = jnp.pad(cls, ((0, 0), (0, 1)),
                               constant_values=t.pad_cls)
-            # static (trace-time) gather maps: row-block r's documents read
-            # row r's chunk boundaries.  A single gather assembles the whole
-            # [C, B, Lmax] buffer — per-piece stack/concat assembly miscompiles
-            # under jit-of-shard_map resharding on jax<0.5 (values arrive
-            # psum-scaled by the chunk extent), a gather does not.
-            col_idx = np.full((n_chunks, b, lmax), w, np.int32)
-            la_idx = np.full((n_chunks, b), w, np.int32)
-            la2_idx = np.full((n_chunks, b), w, np.int32)
-            ex_np = np.zeros((n_chunks, b), bool)
-            for r in range(self.doc_shards):
-                rsel = slice(r * rps, (r + 1) * rps)
-                for ci, (s0, e0) in enumerate(row_bounds[r]):
-                    span = np.arange(lmax)
-                    col_idx[ci, rsel] = np.where(span < e0 - s0, s0 + span, w)
-                    if s0 > 0:
-                        la_idx[ci, rsel] = s0 - 1
-                    if s0 > 1:
-                        la2_idx[ci, rsel] = s0 - 2
-                    elif s0 == 1 and t.spec_r == 2:
-                        # ChunkLayout.MIN_CUT keeps interior cuts >= 2
-                        raise ValueError("spec_r=2 boundary keys need chunk "
-                                         "cuts >= 2 symbols into the stream")
-                    ex_np[ci, rsel] = bool(row_exact[r][ci])
+            # static (trace-time) chunk boundaries per (chunk, row):
+            # row-block r's documents read row r's chunks.  The [C, B, Lmax]
+            # gather map is built on device from them — baked, it would be
+            # a program constant four times the size of the byte buffer
+            def per_row(rows):  # [Dd, C] -> [C, B]
+                return np.repeat(np.asarray(rows).T, rps, axis=1)
+
+            starts = per_row([[s0 for s0, _ in rb] for rb in row_bounds])
+            lens = per_row([[e0 - s0 for s0, e0 in rb] for rb in row_bounds])
+            ex_np = per_row(row_exact).astype(bool)
+            if t.spec_r == 2 and (starts == 1).any():
+                # ChunkLayout.MIN_CUT keeps interior cuts >= 2
+                raise ValueError("spec_r=2 boundary keys need chunk cuts "
+                                 ">= 2 symbols into the stream")
+            la_idx = np.where(starts > 0, starts - 1, w).astype(np.int32)
+            la2_idx = np.where(starts > 1, starts - 2, w).astype(np.int32)
+            span = jnp.arange(lmax, dtype=jnp.int32)
+            col_idx = jnp.where(span < jnp.asarray(lens, jnp.int32)[..., None],
+                                jnp.asarray(starts, jnp.int32)[..., None]
+                                + span, jnp.int32(w))
             rows_b = jnp.arange(b, dtype=jnp.int32)
-            chunk_buf = cls_pad[rows_b[None, :, None],
-                                jnp.asarray(col_idx)]    # [C, B, Lmax]
+            chunk_buf = cls_pad[rows_b[None, :, None], col_idx]  # [C, B, Lmax]
             la1 = cls_pad[rows_b[None, :], jnp.asarray(la_idx)]  # [C, B]
             if t.spec_r == 2:
                 la2 = cls_pad[rows_b[None, :], jnp.asarray(la2_idx)]

@@ -20,7 +20,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from ..jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["hierarchical_merge_lvecs", "flat_merge_lvecs", "hierarchical_mean",

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from ..jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["init_error_state", "quantize", "dequantize",

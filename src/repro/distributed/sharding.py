@@ -23,7 +23,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["param_specs", "batch_specs", "cache_specs", "state_specs",
-           "named", "opt_state_specs", "matcher_table_specs",
+           "named", "opt_state_specs",
            "matcher_chunk_specs", "matcher_lane_specs", "doc_batch_spec"]
 
 STACK_KEYS = {"layers", "groups", "enc", "dec"}
@@ -168,24 +168,6 @@ def state_specs(state: Any, mesh, batch: int) -> Any:
         return P(*spec)
 
     return jax.tree.map(spec_of, state)
-
-
-def matcher_table_specs(mesh) -> dict[str, P]:
-    """PartitionSpecs for the packed matcher tables (engine/plan.DeviceTables).
-
-    Transition/candidate tables are small (VMEM-resident on TPU) and read by
-    every chunk lane, so they replicate on every device regardless of mesh
-    shape — the sharded executor moves lane *states*, never tables.
-    """
-    return {
-        "table_pad": P(None, None),        # [Q, n_cls + 1]
-        "cand_pad": P(None, None, None),   # [n_cls + 1, K, S]
-        "cidx_pad": P(None, None),         # [n_cls + 1, Q]
-        "starts": P(None),                 # [K]
-        "sinks": P(None),                  # [K]
-        "byte_to_class": P(None),          # [256]
-        "absorbing": P(None),              # [Q]
-    }
 
 
 def matcher_chunk_specs(mesh) -> tuple[tuple[P, P, P, P], P]:

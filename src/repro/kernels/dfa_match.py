@@ -15,9 +15,13 @@ TPU adaptation of the paper's AVX2 gather loop (Listing 2).  Design:
     chunk blocks ride the leading ("parallel") dimension.
 
 Grid: ``(C / c_blk, L / l_blk)``; BlockSpecs stream symbol blocks HBM->VMEM
-while the carry stays resident.  On real Mosaic the in-kernel ``jnp.take``
-lowers to the TPU dynamic-gather unit; correctness is validated against
-``ref.spec_match_ref`` in interpret mode (this container is CPU-only).
+while the carry stays resident.  Correctness is validated against
+``ref.spec_match_ref`` in interpret mode.  Mosaic (the TPU compiler) refuses
+these kernels as written: the 1-D ``jnp.take`` from the flat table is not a
+supported gather (2-D gathers must stay inside one 8x128 source tile), the
+value-level ``dynamic_slice`` is unimplemented, and the ``(1, c)`` /
+``(1, 1)`` blocks are not (8, 128)-tiled (tests/test_chip_compile.py keeps
+each refusal as a strict xfail).
 """
 
 from __future__ import annotations
@@ -28,8 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from .pallas_compat import CompilerParams
 
 __all__ = ["spec_match_kernel", "spec_match_pallas",
            "spec_match_merge_kernel", "spec_match_merge_pallas",
@@ -73,7 +75,8 @@ def spec_match_kernel(table_ref, chunks_ref, init_ref, out_ref, carry_ref, *,
 @functools.partial(jax.jit, static_argnames=("c_blk", "l_blk", "interpret"))
 def spec_match_pallas(table: jnp.ndarray, chunks: jnp.ndarray,
                       init_states: jnp.ndarray, *, c_blk: int = 8,
-                      l_blk: int = 512, interpret: bool = True) -> jnp.ndarray:
+                      l_blk: int = 512,
+                      interpret: bool | None = None) -> jnp.ndarray:
     """Pallas-backed equivalent of ``ref.spec_match_ref``.
 
     table [Q, n_cls] int32; chunks [C, L]; init_states [C, S].
@@ -88,6 +91,7 @@ def spec_match_pallas(table: jnp.ndarray, chunks: jnp.ndarray,
 
     kernel = functools.partial(spec_match_kernel, n_classes=n_cls,
                                l_blocks=l_blocks)
+    from .ops import _interpret  # deferred: ops imports this module
     return pl.pallas_call(
         kernel,
         grid=(c // c_blk, l_blocks),
@@ -99,9 +103,9 @@ def spec_match_pallas(table: jnp.ndarray, chunks: jnp.ndarray,
         out_specs=pl.BlockSpec((c_blk, s), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((c, s), jnp.int32),
         scratch_shapes=[pltpu.VMEM((c_blk, s), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(flat, chunks.astype(jnp.int32), init_states.astype(jnp.int32))
 
 
@@ -276,6 +280,7 @@ def _merge_pallas_call(kernel_fn, table, chunks, init_states, lookahead,
     kernel = functools.partial(kernel_fn, n_cls_pad=n_cls_pad,
                                l_blocks=l_blocks, n_patterns=k,
                                pad_cls=pad_cls, early_exit=early_exit)
+    from .ops import _interpret  # deferred: ops imports this module
     out, skipped = pl.pallas_call(
         kernel,
         grid=(b, l_blocks),
@@ -294,9 +299,9 @@ def _merge_pallas_call(kernel_fn, table, chunks, init_states, lookahead,
                    jax.ShapeDtypeStruct((b, 1), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((c, s_tot), jnp.int32),
                         pltpu.SMEM((1,), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(flat, chunks.astype(jnp.int32), init_states.astype(jnp.int32),
       lookahead.astype(jnp.int32), cand_index.astype(jnp.int32),
       sinks.astype(jnp.int32), absorbing.astype(jnp.int32))
@@ -310,7 +315,7 @@ def spec_match_merge_pallas(table: jnp.ndarray, chunks: jnp.ndarray,
                             cand_index: jnp.ndarray, sinks: jnp.ndarray,
                             absorbing: jnp.ndarray, *, pad_cls: int,
                             l_blk: int = 512, early_exit: bool = True,
-                            interpret: bool = True
+                            interpret: bool | None = None
                             ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Pallas-backed equivalent of ``ref.spec_match_merge_ref``.
 
@@ -338,7 +343,7 @@ def spec_match_merge_lanes_pallas(table: jnp.ndarray, chunks: jnp.ndarray,
                                   cand_index: jnp.ndarray, sinks: jnp.ndarray,
                                   absorbing: jnp.ndarray, *, pad_cls: int,
                                   l_blk: int = 512, early_exit: bool = True,
-                                  interpret: bool = True
+                                  interpret: bool | None = None
                                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Pallas-backed equivalent of ``ref.spec_match_merge_lanes_ref``.
 

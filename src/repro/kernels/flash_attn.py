@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 __all__ = ["flash_attn_kernel", "flash_attn_pallas"]
 
 NEG = -1e30
@@ -97,7 +95,7 @@ def flash_attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attn_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                       q_blk: int = 256, kv_blk: int = 256,
                       causal: bool = True, window: int = 0,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool | None = None) -> jnp.ndarray:
     """q [BH, T, D]; k, v [BH, S, D] -> out [BH, T, D].
 
     BH = batch x heads (GQA callers index k/v per group before the call).
@@ -112,6 +110,7 @@ def flash_attn_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     kernel = functools.partial(
         flash_attn_kernel, q_blk=q_blk, kv_blk=kv_blk, ns=ns, causal=causal,
         window=window, scale=d ** -0.5)
+    from .ops import _interpret  # deferred: ops imports this module
     return pl.pallas_call(
         kernel,
         grid=(bh, nq, ns),
@@ -127,7 +126,7 @@ def flash_attn_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((q_blk,), jnp.float32),
             pltpu.VMEM((q_blk, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(q, k, v)

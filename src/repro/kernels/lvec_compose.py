@@ -36,8 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 __all__ = ["lvec_compose_kernel", "lvec_compose_pallas",
            "spec_compose_lanes_kernel", "spec_compose_lanes_pallas",
            "spec_compose_lanes_tree_kernel",
@@ -70,7 +68,7 @@ def lvec_compose_kernel(maps_ref, out_ref, carry_ref, *, c_blocks: int):
 
 @functools.partial(jax.jit, static_argnames=("c_blk", "interpret"))
 def lvec_compose_pallas(maps: jnp.ndarray, *, c_blk: int = 8,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: bool | None = None) -> jnp.ndarray:
     """Pallas-backed equivalent of ``ref.lvec_compose_ref``.
 
     maps [C, Q] int32 with C % c_blk == 0; returns the composed map [Q].
@@ -79,6 +77,7 @@ def lvec_compose_pallas(maps: jnp.ndarray, *, c_blk: int = 8,
     assert c % c_blk == 0, (c, c_blk)
     c_blocks = c // c_blk
     kernel = functools.partial(lvec_compose_kernel, c_blocks=c_blocks)
+    from .ops import _interpret  # deferred: ops imports this module
     return pl.pallas_call(
         kernel,
         grid=(c_blocks,),
@@ -86,9 +85,9 @@ def lvec_compose_pallas(maps: jnp.ndarray, *, c_blk: int = 8,
         out_specs=pl.BlockSpec((q,), lambda j: (0,)),
         out_shape=jax.ShapeDtypeStruct((q,), jnp.int32),
         scratch_shapes=[pltpu.VMEM((q,), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(maps.astype(jnp.int32))
 
 
@@ -136,7 +135,7 @@ def spec_compose_lanes_kernel(lanes_ref, keys_ref, cidx_ref, sinks_ref,
 def spec_compose_lanes_pallas(lanes: jnp.ndarray, keys: jnp.ndarray,
                               cand_index: jnp.ndarray, sinks: jnp.ndarray, *,
                               pad_key: int, n_blk: int = 8,
-                              interpret: bool = True) -> jnp.ndarray:
+                              interpret: bool | None = None) -> jnp.ndarray:
     """Block-sequential grid-carry compose of [B, N, K, S] lane-map runs.
 
     N % n_blk == 0 (pad trailing elements with ``pad_key`` keys — identity).
@@ -149,6 +148,7 @@ def spec_compose_lanes_pallas(lanes: jnp.ndarray, keys: jnp.ndarray,
     kernel = functools.partial(spec_compose_lanes_kernel,
                                n_blocks=n_blocks, pad_key=pad_key)
     nk, q = cand_index.shape
+    from .ops import _interpret  # deferred: ops imports this module
     return pl.pallas_call(
         kernel,
         grid=(b, n_blocks),
@@ -161,9 +161,9 @@ def spec_compose_lanes_pallas(lanes: jnp.ndarray, keys: jnp.ndarray,
         out_specs=pl.BlockSpec((1, k, s), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, k, s), jnp.int32),
         scratch_shapes=[pltpu.VMEM((k, s), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(lanes.astype(jnp.int32), keys.astype(jnp.int32),
       cand_index.astype(jnp.int32), sinks.astype(jnp.int32))
 
@@ -203,13 +203,15 @@ def spec_compose_lanes_tree_kernel(lanes_ref, keys_ref, cidx_ref, sinks_ref,
 def spec_compose_lanes_tree_pallas(lanes: jnp.ndarray, keys: jnp.ndarray,
                                    cand_index: jnp.ndarray,
                                    sinks: jnp.ndarray, *, pad_key: int,
-                                   interpret: bool = True) -> jnp.ndarray:
+                                   interpret: bool | None = None
+                                   ) -> jnp.ndarray:
     """Tree-reduce compose of [B, N, K, S] runs; N must be a power of two."""
     b, n, k, s = lanes.shape
     assert n >= 1 and (n & (n - 1)) == 0, n
     kernel = functools.partial(spec_compose_lanes_tree_kernel,
                                pad_key=pad_key)
     nk, q = cand_index.shape
+    from .ops import _interpret  # deferred: ops imports this module
     return pl.pallas_call(
         kernel,
         grid=(b,),
@@ -221,8 +223,8 @@ def spec_compose_lanes_tree_pallas(lanes: jnp.ndarray, keys: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, k, s), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, k, s), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(lanes.astype(jnp.int32), keys.astype(jnp.int32),
       cand_index.astype(jnp.int32), sinks.astype(jnp.int32))

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 __all__ = ["onehot_match_kernel", "onehot_block_maps_pallas", "build_pmats"]
 
 
@@ -61,7 +59,8 @@ def onehot_match_kernel(syms_ref, pmats_ref, out_ref, *, q: int):
 
 @functools.partial(jax.jit, static_argnames=("l_blk", "interpret"))
 def onehot_block_maps_pallas(table: jnp.ndarray, symbols: jnp.ndarray, *,
-                             l_blk: int = 256, interpret: bool = True) -> jnp.ndarray:
+                             l_blk: int = 256,
+                             interpret: bool | None = None) -> jnp.ndarray:
     """Pallas-backed equivalent of ``ref.onehot_block_maps_ref``.
 
     table [Q, n_cls] int32, symbols [L] int32 with L % l_blk == 0.
@@ -72,6 +71,7 @@ def onehot_block_maps_pallas(table: jnp.ndarray, symbols: jnp.ndarray, *,
     assert l % l_blk == 0, (l, l_blk)
     pmats = build_pmats(table)
     kernel = functools.partial(onehot_match_kernel, q=q)
+    from .ops import _interpret  # deferred: ops imports this module
     return pl.pallas_call(
         kernel,
         grid=(l // l_blk,),
@@ -81,7 +81,7 @@ def onehot_block_maps_pallas(table: jnp.ndarray, symbols: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((1, q), lambda b: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((l // l_blk, q), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(symbols.astype(jnp.int32), pmats)

@@ -1,11 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Each op pads/blocks its inputs to kernel-legal shapes, dispatches to the
-Pallas kernel (interpret mode off-TPU, compiled on TPU), and exposes the same
-semantics as its ``ref.py`` oracle.  ``spec_match`` additionally implements
-the gather-vs-MXU crossover (DESIGN.md §2, beyond-paper): wide speculation
-(S approaching Q) on small-Q DFAs is cheaper as one-hot matmuls with
-log-depth composition than as an L-deep serial gather chain.
+Pallas kernel (interpret mode off-TPU, compiled on TPU: ``_interpret``), and
+exposes the same semantics as its ``ref.py`` oracle.  ``spec_match``
+additionally implements the gather-vs-MXU crossover (DESIGN.md §2,
+beyond-paper): wide speculation (S approaching Q) on small-Q DFAs is
+cheaper as one-hot matmuls with log-depth composition than as an L-deep
+serial gather chain.
 """
 
 from __future__ import annotations
@@ -33,8 +34,12 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _interpret() -> bool:
-    return not on_tpu()
+def _interpret(interpret: bool | None = None) -> bool:
+    """Resolve a kernel's ``interpret`` option: ``None`` interprets off the
+    TPU and compiles on it.  Every raw ``*_pallas`` wrapper defaults to
+    ``None`` and resolves here, so no caller runs the interpreter on a TPU
+    without asking for it."""
+    return not on_tpu() if interpret is None else bool(interpret)
 
 
 def _pick_block(n: int, target: int) -> int:
@@ -93,7 +98,6 @@ def spec_match(table: jnp.ndarray, chunks: jnp.ndarray,
                init_states: jnp.ndarray, *, use_mxu: bool | None = None,
                interpret: bool | None = None) -> jnp.ndarray:
     """Match [C] chunks x [S] lanes; semantics of ``ref.spec_match_ref``."""
-    interpret = _interpret() if interpret is None else interpret
     c, l = chunks.shape
     q = table.shape[0]
     s = init_states.shape[1]
@@ -156,7 +160,6 @@ def spec_match_merge(table: jnp.ndarray, chunks: jnp.ndarray,
     exit, and the block size the lowering needs to convert that count into an
     exit position.
     """
-    interpret = _interpret() if interpret is None else interpret
     pad_key = pad_cls if pad_key is None else pad_key
     chunks, l_blk = _pad_merge_chunks(chunks, pad_cls, l_blk)
     out, skipped = spec_match_merge_pallas(
@@ -182,7 +185,6 @@ def spec_match_merge_lanes(table: jnp.ndarray, chunks: jnp.ndarray,
     ``(lanes [B, K, S], skipped [B], l_blk)``.  ``pad_key`` as in
     ``spec_match_merge``.
     """
-    interpret = _interpret() if interpret is None else interpret
     pad_key = pad_cls if pad_key is None else pad_key
     chunks, l_blk = _pad_merge_chunks(chunks, pad_cls, l_blk)
     out, skipped = spec_match_merge_lanes_pallas(
@@ -216,7 +218,6 @@ def spec_compose_lanes(lane_maps: jnp.ndarray, entry_keys: jnp.ndarray,
     matches the oracle everywhere, ``"tree"`` may differ from it on pad
     lanes only.  No decision path reads a pad lane.
     """
-    interpret = _interpret() if interpret is None else interpret
     b, n, k, s = lane_maps.shape
     assert n >= 1, "empty runs are the caller's fast path"
     if mode == "tree":
@@ -244,7 +245,6 @@ def spec_compose_lanes(lane_maps: jnp.ndarray, entry_keys: jnp.ndarray,
 
 def lvec_compose(maps: jnp.ndarray, *, interpret: bool | None = None) -> jnp.ndarray:
     """Compose [C, Q] maps left-to-right -> [Q]; see ``ref.lvec_compose_ref``."""
-    interpret = _interpret() if interpret is None else interpret
     c, q = maps.shape
     c_blk, c_pad = _pad_to_block(c, 8)
     if c_pad != c:  # identity maps compose as no-ops
@@ -263,7 +263,6 @@ def onehot_block_maps(table: jnp.ndarray, symbols: jnp.ndarray, *,
     trailing block maps are identity permutations (no-ops under
     composition).
     """
-    interpret = _interpret() if interpret is None else interpret
     l = symbols.shape[0]
     l_blk, l_pad = _pad_to_block(l, block_l)
     if l_pad != l:
@@ -277,7 +276,6 @@ def token_mask(states: jnp.ndarray, allowed: jnp.ndarray, logits: jnp.ndarray,
                *, neg: float = -1e30,
                interpret: bool | None = None) -> jnp.ndarray:
     """Fused grammar mask; see ``ref.token_mask_ref``.  Pads V to the tile."""
-    interpret = _interpret() if interpret is None else interpret
     b, v = logits.shape
     v_blk, v_pad = _pad_to_block(v, 2048)
     if v_pad != v:  # ragged vocab: pad to the tile boundary (masked -> neg)
@@ -300,7 +298,6 @@ def flash_attn(q, k, v, *, causal: bool = True, window: int = 0,
     EXPERIMENTS.md §Perf (tiles stay in VMEM).  The XLA path
     (models.attention_core.flash_attention) remains the autodiff/dry-run path.
     """
-    interpret = _interpret() if interpret is None else interpret
     t, st = q.shape[1], k.shape[1]
     return flash_attn_pallas(q, k, v, q_blk=_pick_block(t, q_blk),
                              kv_blk=_pick_block(st, kv_blk), causal=causal,

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 __all__ = ["token_mask_kernel", "token_mask_pallas"]
 
 
@@ -37,7 +35,7 @@ def token_mask_kernel(states_ref, allowed_ref, logits_ref, out_ref, *, neg: floa
 @functools.partial(jax.jit, static_argnames=("v_blk", "neg", "interpret"))
 def token_mask_pallas(states: jnp.ndarray, allowed: jnp.ndarray,
                       logits: jnp.ndarray, *, v_blk: int = 2048,
-                      neg: float = -1e30, interpret: bool = True) -> jnp.ndarray:
+                      neg: float = -1e30, interpret: bool | None = None) -> jnp.ndarray:
     """Pallas-backed equivalent of ``ref.token_mask_ref``.
 
     states [B] int32; allowed [Q, V] uint8/bool; logits [B, V] float.
@@ -47,6 +45,7 @@ def token_mask_pallas(states: jnp.ndarray, allowed: jnp.ndarray,
     q = allowed.shape[0]
     assert v % v_blk == 0, (v, v_blk)
     kernel = functools.partial(token_mask_kernel, neg=neg)
+    from .ops import _interpret  # deferred: ops imports this module
     return pl.pallas_call(
         kernel,
         grid=(b, v // v_blk),
@@ -57,7 +56,7 @@ def token_mask_pallas(states: jnp.ndarray, allowed: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, v_blk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, v), logits.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
+        interpret=_interpret(interpret),
     )(states.astype(jnp.int32), allowed.astype(jnp.uint8), logits)

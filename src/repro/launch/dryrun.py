@@ -104,7 +104,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool):
 def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str) -> dict:
     import jax
 
-    from ..jax_compat import set_mesh
+    from jax import set_mesh
 
     multi = mesh_kind == "multi"
     t0 = time.time()

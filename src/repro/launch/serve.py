@@ -27,6 +27,7 @@ from ..configs import get_config, reduce_for_smoke
 from ..core import compile_regex
 from ..models import api
 from ..serving import GrammarConstraint, ServeConfig, ServingEngine
+from .compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -56,6 +57,7 @@ def main() -> None:
         ap.error("--snapshot-dir requires --stream (it checkpoints the "
                  "streaming prefill cursors)")
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)

@@ -22,7 +22,7 @@ import numpy as np
 import jax
 
 from ..configs import get_config, reduce_for_smoke
-from ..jax_compat import set_mesh
+from jax import set_mesh
 from ..data import CorpusConfig, CorpusFilter, LoaderConfig, data_stream, generate_documents
 from ..distributed import sharding as shr
 from ..training import AdamWConfig, CheckpointManager, TrainOptions

@@ -114,7 +114,7 @@ def _moe_sharded(p, x, top_k, capacity_factor, mesh, expert_axis, batch_axes):
     builds [E, C_loc, d] send buffers, and exchanges them so each device runs
     its resident experts on tokens from every peer.
     """
-    from ..jax_compat import shard_map
+    from jax import shard_map
 
     axes = tuple(a for a in batch_axes if a in mesh.axis_names)
     ep = mesh.shape[expert_axis]

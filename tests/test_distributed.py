@@ -136,7 +136,7 @@ def test_train_step_on_small_production_mesh():
     """Full sharded train step (FSDP+TP+EP) on a (2,2,2) mesh, MoE arch."""
     run_in_subprocess("""
         import numpy as np, jax
-        from repro.jax_compat import set_mesh
+        from jax import set_mesh
         from repro.configs import ShapeSpec, get_config, reduce_for_smoke
         from repro.models import api
         from repro.training.train_loop import (TrainOptions,

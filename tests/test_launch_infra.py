@@ -40,7 +40,7 @@ def test_census_collectives_on_forced_devices():
                PYTHONPATH=os.path.join(repo, "src"))
     body = textwrap.dedent("""
         import jax, jax.numpy as jnp
-        from repro.jax_compat import set_mesh
+        from jax import set_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.launch.hlo_census import hlo_census
         mesh = jax.make_mesh((8,), ("data",))
@@ -117,3 +117,47 @@ def test_roofline_count_params_dense():
     total, active = count_params("llama3-8b")
     assert 7e9 < total < 9.5e9, total
     assert total == active
+
+
+def _run_cache_probe(env_dir, compile_once: bool) -> str:
+    """In a fresh process: call use_compile_cache() and, optionally, compile
+    one program with the cache's size/time thresholds at zero."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.launch.compile_cache import use_compile_cache\n"
+            "print(use_compile_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    if compile_once:
+        code += ("jax.config.update("
+                 "'jax_persistent_cache_min_compile_time_secs', 0)\n"
+                 "jax.config.update("
+                 "'jax_persistent_cache_min_entry_size_bytes', 0)\n"
+                 "jax.jit(lambda x: x * 3 + 1)(jnp.arange(8))"
+                 ".block_until_ready()\n")
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(repo, "src"))
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=repo,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return out.stdout
+
+
+def test_compile_cache_lands_in_env_dir(tmp_path):
+    lines = _run_cache_probe(tmp_path, compile_once=True).split()
+    assert lines == [str(tmp_path), str(tmp_path)]
+    assert any(tmp_path.iterdir())  # the compiled program was cached there
+
+
+def test_compile_cache_defaults_to_repo_dir():
+    import pathlib
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    lines = _run_cache_probe(None, compile_once=False).split()
+    assert lines == [str(repo / ".jax_cache")] * 2
