@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the device,
+averaged over chips (``tracing.TraceSummary.idle_pct``)."""
+
+
+def read(ctx):
+    return ctx.trace.idle_pct if ctx.trace is not None else None
