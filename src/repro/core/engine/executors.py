@@ -7,7 +7,7 @@ executor backend is a *lowering* of that one plan, not a family of
 hand-rolled variants: every backend exposes exactly
 
     run(plan, bytes_buf, lengths, *, layout=None, entry=None,
-        entry_classes=None) -> (finals, absorbed_pos)
+        entry_classes=None) -> (finals, absorbed_pos, steps)
 
 and lowers a plan at most once (``lower``; compiled programs are cached by
 ``plan.key``).  All lowerings consume the same operands —
@@ -25,10 +25,22 @@ and lowers a plan at most once (``lower``; compiled programs are cached by
     [B]`` boundary classes (``ENTRY_LANES`` — the streaming device merge),
 
 and must be bit-identical to per-document sequential matching.  The return
-is ``(finals [B, K], absorbed_pos [B])`` — or ``([B, K, S], pos)`` for lane
-plans — where ``absorbed_pos`` is the scan position (chunk-local for spec,
-stream for seq) at which every lane of a document became absorbing, or the
-``NO_EXIT`` sentinel.
+is ``(finals [B, K], absorbed_pos [B], steps [n])`` — or ``([B, K, S], pos,
+steps)`` for lane plans — where ``absorbed_pos`` is the scan position
+(chunk-local for spec, stream for seq) at which every lane of a document
+became absorbing, or the ``NO_EXIT`` sentinel, and ``steps`` holds the
+symbol steps each of the program's ``n`` scan loops ran.  The loops split
+the program's rows evenly (documents for seq plans, document-chunks for
+spec plans), so ``rows / n * steps.sum()`` is the row-steps the gather chain
+executed: one loop on a single device, one per shard on a mesh, one per
+document for the Pallas kernels (derived from their skipped blocks).
+
+Every compiled program is named for what it runs (``seq_scan``,
+``spec_scan``, ``compose_scan``), and its stages sit in ``jax.named_scope``
+blocks (``classify``, ``seed``, ``chunk_scan``, ``merge``,
+``compose_cursor``), so a profiler trace can tell them apart.  The first
+call of a newly lowered program, where jit traces and compiles, runs inside
+a ``repro.compile`` profiler span.
 
 Backends (the three lowerings):
 
@@ -63,12 +75,14 @@ side effect fires at trace time only).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional, Protocol
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..lvector import merge_scan_lanes_jnp
 from .plan import (ENTRY_LANES, ENTRY_STARTS, ENTRY_STATES, DeviceTables,
@@ -88,13 +102,23 @@ class Executor(Protocol):
             lengths: jnp.ndarray, *, layout=None,
             entry: Optional[jnp.ndarray] = None,
             entry_classes: Optional[jnp.ndarray] = None
-            ) -> tuple[jnp.ndarray, jnp.ndarray]: ...
+            ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]: ...
 
     def steps_for(self, layout) -> int: ...
 
 
 def _prev_pow2(n: int) -> int:
     return 1 if n <= 1 else 1 << (n.bit_length() - 1)
+
+
+def named_jit(fn, name: str):
+    """``jax.jit`` of ``fn`` under a stable program name (``jit_<name>`` in
+    the compiled module and the profiler's ``XLA Modules`` line)."""
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program)
 
 
 class LaneExecutor:
@@ -133,13 +157,15 @@ class LaneExecutor:
             lengths: jnp.ndarray, *, layout=None,
             entry: Optional[jnp.ndarray] = None,
             entry_classes: Optional[jnp.ndarray] = None
-            ) -> tuple[jnp.ndarray, jnp.ndarray]:
-        fn = self.lower(plan, layout=layout, batch=int(bytes_buf.shape[0]))
-        if plan.entry == ENTRY_STARTS:
-            return fn(bytes_buf, lengths)
-        if plan.entry == ENTRY_STATES:
-            return fn(bytes_buf, lengths, entry)
-        return fn(bytes_buf, lengths, entry, entry_classes)
+            ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+        batch = int(bytes_buf.shape[0])
+        fresh = self._plan_key(plan, batch) not in self._lowered
+        fn = self.lower(plan, layout=layout, batch=batch)
+        args = {ENTRY_STARTS: (), ENTRY_STATES: (entry,),
+                ENTRY_LANES: (entry, entry_classes)}[plan.entry]
+        with (TraceAnnotation("repro.compile") if fresh
+              else contextlib.nullcontext()):
+            return fn(bytes_buf, lengths, *args)
 
     def lower(self, plan: LanePlan, *, layout=None, batch: int = 0):
         """Compiled program for one plan (cached; lowering happens once)."""
@@ -201,8 +227,8 @@ class LaneExecutor:
         self._lowered.clear()
         self.lowering_kinds.clear()
 
-    def _jit_lowering(self, body):
-        """jit a lowering body under the retrace counter.
+    def _jit_lowering(self, body, name: str):
+        """jit a lowering body, named ``name``, under the retrace counter.
 
         ``body`` takes the plan's runtime operands positionally —
         ``(bytes_buf, lengths[, entry[, entry_classes]])`` per
@@ -211,11 +237,11 @@ class LaneExecutor:
         donated: no output has the byte buffer's shape and dtype, so XLA
         could alias none of it.
         """
-        def impl(*args):
+        def counted(*args):
             self.traces += 1  # side effect fires at trace time only
             return body(*args)
 
-        return jax.jit(impl)
+        return named_jit(counted, name)
 
     def _lower(self, plan: LanePlan, layout, batch: int):
         """Backend hook: build the compiled program of one plan."""
@@ -230,6 +256,7 @@ class LaneExecutor:
     # -- stage: classify (the retired host numpy path lives in
     # kernels/ref.classify_pad_ref as the oracle) ---------------------------
 
+    @jax.named_scope("classify")
     def _classify(self, bytes_buf: jnp.ndarray, lengths: jnp.ndarray) -> jnp.ndarray:
         """bytes [B, W] + lengths -> [B, W] class ids, pad_cls past the end."""
         cls = self.t.byte_to_class_j[bytes_buf.astype(jnp.int32)]
@@ -239,6 +266,7 @@ class LaneExecutor:
 
     # -- stage: entry seed --------------------------------------------------
 
+    @jax.named_scope("seed")
     def _seed_rows(self, plan: LanePlan, b: int, entry, entry_cls) -> jnp.ndarray:
         """Entry-seed stage for sequential rows: [B, K] exact states, or
         [B, K, S] candidate lanes for lane plans."""
@@ -249,6 +277,7 @@ class LaneExecutor:
             return entry.astype(jnp.int32)
         return self.t.cand_pad_j[entry_cls]            # [B, K, S]
 
+    @jax.named_scope("seed")
     def _seed_chunk0(self, plan: LanePlan, b: int, entry, entry_cls) -> jnp.ndarray:
         """Entry-seed stage for spec chunk 0: [B, 1, K, S] lanes."""
         k, s = self.t.n_patterns, self.t.i_max
@@ -259,10 +288,11 @@ class LaneExecutor:
 
     # -- stage: chunk scan with absorbing-state early exit -------------------
 
+    @jax.named_scope("chunk_scan")
     def _segmented_match(self, sym_t: jnp.ndarray, states: jnp.ndarray,
                          eff_len: jnp.ndarray, scan_len: int,
                          early_exit: bool = True
-                         ) -> tuple[jnp.ndarray, jnp.ndarray]:
+                         ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
         """Scan ``states [R, S]`` through ``sym_t [L, R]`` symbol columns in
         segments, stopping once every document is *done*: all its lanes are
         absorbing, or the scan has passed its real symbols (``eff_len [B]``
@@ -270,11 +300,12 @@ class LaneExecutor:
         so they never pin the loop to the full scan).
 
         Rows are doc-major (R = B * rows_per_doc).  Returns (final states,
-        absorbed_pos [B]) with ``absorbed_pos`` the first segment boundary at
-        which a document's lanes were all absorbing (sentinel ``NO_EXIT``
-        otherwise).  Exactness: absorbing states self-loop on every class and
-        padding is the identity column, so skipping the remaining symbols of
-        a done document is bit-identical.
+        absorbed_pos [B], steps [1]) with ``absorbed_pos`` the first segment
+        boundary at which a document's lanes were all absorbing (sentinel
+        ``NO_EXIT`` otherwise) and ``steps`` the symbol steps the loop ran
+        over all R rows.  Exactness: absorbing states self-loop on every
+        class and padding is the identity column, so skipping the remaining
+        symbols of a done document is bit-identical.
         """
         table = self.t.table_pad_j
         absorbing = self.t.absorbing_j
@@ -289,7 +320,8 @@ class LaneExecutor:
         segs = min(self.early_exit_segments if early_exit else 1, scan_len)
         pos0 = jnp.full((b,), NO_EXIT, jnp.int32)
         if segs <= 1 or scan_len == 0:
-            return seg_scan(states, sym_t), pos0
+            return (seg_scan(states, sym_t), pos0,
+                    jnp.full((1,), scan_len, jnp.int32))
         seg_len = scan_len // segs
 
         def cond(carry):
@@ -307,12 +339,13 @@ class LaneExecutor:
             done = doc_abs | (boundary >= eff_len.astype(jnp.int32))
             return st, g + 1, pos, done.all()
 
-        states, _, pos, _ = jax.lax.while_loop(
+        states, g, pos, _ = jax.lax.while_loop(
             cond, body, (states, jnp.int32(0), pos0, jnp.bool_(False)))
-        return states, pos
+        return states, pos, (g * seg_len).astype(jnp.int32)[None]
 
     # -- stage: device cursor merge (lane plans) -----------------------------
 
+    @jax.named_scope("compose_cursor")
     def _compose_cursor(self, cursor_lanes: jnp.ndarray,
                         seg_lanes: jnp.ndarray,
                         entry_cls: jnp.ndarray) -> jnp.ndarray:
@@ -352,7 +385,7 @@ class LaneExecutor:
                                            axis=1)
                 return out[:, -1]
 
-            fn = self._jit_lowering(body)
+            fn = self._jit_lowering(body, "compose_scan")
             self._lowered[key] = fn
             self.lowering_kinds[key] = "compose-scan"
         return fn(jnp.asarray(lane_maps, jnp.int32),
@@ -369,18 +402,18 @@ class LaneExecutor:
         cls = self._classify(bytes_buf, lengths)
         init = self._seed_rows(plan, b, entry, entry_cls)
         rows = init.reshape(b, -1).astype(jnp.int32)
-        finals, pos = self._segmented_match(cls.T, rows,
-                                            jnp.minimum(lengths, w), w,
-                                            early_exit=plan.early_exit)
+        finals, pos, steps = self._segmented_match(
+            cls.T, rows, jnp.minimum(lengths, w), w,
+            early_exit=plan.early_exit)
         if plan.entry == ENTRY_LANES:
             seg = finals.reshape(b, self.t.n_patterns, self.t.i_max)
             return self._compose_cursor(entry.astype(jnp.int32), seg,
-                                        entry_cls), pos
-        return finals, pos
+                                        entry_cls), pos, steps
+        return finals, pos, steps
 
     def _lower_seq_local(self, plan: LanePlan):
         return self._jit_lowering(
-            lambda *args: self._seq_body(plan, *args))
+            lambda *args: self._seq_body(plan, *args), "seq_scan")
 
     # -- spec stage bodies (shared by the local jnp and kernel lowerings) ----
 
@@ -404,19 +437,22 @@ class LaneExecutor:
         k, s = t.n_patterns, t.i_max
         cls = self._classify(bytes_buf, lengths)
         body = cls.reshape(b, c, lc)
-        last1 = body[:, :-1, -1]                               # [B, C-1]
-        if t.spec_r == 2:
-            if lc < 2:
-                raise ValueError(
-                    f"spec_r=2 boundary keys need chunk_len >= 2, got {lc}")
-            key = body[:, :-1, -2] * jnp.int32(t.pad_cls) + last1
-            key = jnp.where(last1 == t.pad_cls, jnp.int32(t.pad_key), key)
-        else:
-            key = last1  # r=1: the key *is* the class (pad_cls == pad_key)
-        la = jnp.concatenate([jnp.zeros((b, 1), jnp.int32), key], axis=1)
-        cand = t.cand_pad_j[la[:, 1:]]                         # [B, C-1, K, S]
+        if t.spec_r == 2 and lc < 2:
+            raise ValueError(
+                f"spec_r=2 boundary keys need chunk_len >= 2, got {lc}")
         start = self._seed_chunk0(plan, b, entry, entry_cls)   # [B, 1, K, S]
-        init = jnp.concatenate([start, cand], axis=1).reshape(b, c, k * s)
+        with jax.named_scope("seed"):
+            last1 = body[:, :-1, -1]                           # [B, C-1]
+            if t.spec_r == 2:
+                key = body[:, :-1, -2] * jnp.int32(t.pad_cls) + last1
+                key = jnp.where(last1 == t.pad_cls, jnp.int32(t.pad_key),
+                                key)
+            else:
+                key = last1  # r=1: the key *is* the class (pad == pad_key)
+            la = jnp.concatenate([jnp.zeros((b, 1), jnp.int32), key], axis=1)
+            cand = t.cand_pad_j[la[:, 1:]]                     # [B, C-1, K, S]
+            init = jnp.concatenate([start, cand], axis=1).reshape(b, c,
+                                                                  k * s)
         return body, la, init
 
     def _spec_body(self, plan: LanePlan, bytes_buf: jnp.ndarray,
@@ -440,18 +476,20 @@ class LaneExecutor:
                                            entry_cls)
         sym_t = body.reshape(b * c, lc).T                      # [Lc, B*C]
         # per-chunk effective fill: a doc's deepest chunk-local real symbol
-        lvecs, pos = self._segmented_match(sym_t, init.reshape(b * c, k * s),
-                                           jnp.minimum(lengths, lc), lc,
-                                           early_exit=plan.early_exit)
+        lvecs, pos, steps = self._segmented_match(
+            sym_t, init.reshape(b * c, k * s), jnp.minimum(lengths, lc), lc,
+            early_exit=plan.early_exit)
         lv = lvecs.reshape(b, c, k, s)
         if plan.entry == ENTRY_LANES:
-            seg = kref.spec_merge_lanes_ref(lv, la, t.cidx_pad_j, t.sinks_j,
-                                            pad_cls=t.pad_key)
+            with jax.named_scope("merge"):
+                seg = kref.spec_merge_lanes_ref(lv, la, t.cidx_pad_j,
+                                                t.sinks_j, pad_cls=t.pad_key)
             return self._compose_cursor(entry.astype(jnp.int32), seg,
-                                        entry_cls), pos
-        finals = kref.spec_merge_ref(lv, la, t.cidx_pad_j, t.sinks_j,
-                                     pad_cls=t.pad_key)
-        return finals, pos
+                                        entry_cls), pos, steps
+        with jax.named_scope("merge"):
+            finals = kref.spec_merge_ref(lv, la, t.cidx_pad_j, t.sinks_j,
+                                         pad_cls=t.pad_key)
+        return finals, pos, steps
 
 
 class LocalExecutor(LaneExecutor):
@@ -520,7 +558,7 @@ class LocalExecutor(LaneExecutor):
                     lanes, keys, t.cidx_pad_j, t.sinks_j,
                     pad_key=t.pad_key, mode=mode)
 
-            fn = self._jit_lowering(body)
+            fn = self._jit_lowering(body, "compose_scan")
             self._lowered[key] = fn
             self.lowering_kinds[key] = f"compose-kernel-{mode}"
         return fn(jnp.asarray(lane_maps, jnp.int32),
@@ -537,7 +575,7 @@ class LocalExecutor(LaneExecutor):
             return self._lower_spec_kernel(plan)
         self.lowering_kinds[plan.key] = "spec-jnp"
         return self._jit_lowering(
-            lambda *args: self._spec_body(plan, *args))
+            lambda *args: self._spec_body(plan, *args), "spec_scan")
 
     def _lower_spec_kernel(self, plan: LanePlan):
         """Fused Pallas lowering: bucket-level + in-kernel early exit.
@@ -591,25 +629,30 @@ class LocalExecutor(LaneExecutor):
                     early_exit=plan.early_exit, l_blk=l_blk)
                 return finals, skipped
 
+            def ran(skipped):  # the symbols a document's blocks scanned
+                return (jnp.int32(l_blocks) - skipped) * jnp.int32(l_blk)
+
+            # steps: one scan per document over its C chunks, the blocks it
+            # did not skip (none when the bucket's dispatch was skipped)
             if not plan.early_exit:  # same contract as the jnp lowerings
                 out, skipped = run_kernel()
-                return out, jnp.full((b,), NO_EXIT, jnp.int32), skipped
+                return (out, jnp.full((b,), NO_EXIT, jnp.int32), skipped,
+                        ran(skipped))
             doc_abs = t.absorbing_j[e].reshape(b, -1).all(axis=1)
             done = doc_abs | (lengths.astype(jnp.int32) <= 0)
             zero = jnp.zeros((b,), jnp.int32)
             out, skipped = jax.lax.cond(
                 done.all(), lambda: (e.astype(jnp.int32), zero), run_kernel)
-            pos = jnp.where(skipped > 0,
-                            (jnp.int32(l_blocks) - skipped) * jnp.int32(l_blk),
-                            NO_EXIT)
+            pos = jnp.where(skipped > 0, ran(skipped), NO_EXIT)
             pos = jnp.where(done.all() & doc_abs, jnp.int32(0), pos)
-            return out, pos, skipped
+            return out, pos, skipped, jnp.where(done.all(), 0, ran(skipped))
 
-        jit_fn = self._jit_lowering(lambda *args: kernel_body(plan, *args))
+        jit_fn = self._jit_lowering(lambda *args: kernel_body(plan, *args),
+                                    "spec_scan")
 
         def wrapper(*args):
-            out, pos, skipped = jit_fn(*args)
+            out, pos, skipped, steps = jit_fn(*args)
             self._skipped_log.append(skipped)
-            return out, pos
+            return out, pos, steps
 
         return wrapper

@@ -26,10 +26,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..automata import DFA, PackedDFA, pack_dfas, packed_signature
 from ..partition import capacity_weights
-from .executors import LocalExecutor
+from .executors import LocalExecutor, named_jit
 from .plan import (ENTRY_LANES, ENTRY_STARTS, ENTRY_STATES, DeviceTables,
                    MeshLayout, Planner, layout_device_work, next_pow2)
 
@@ -274,11 +275,16 @@ class Matcher:
         self.num_chunks = self.planner.num_chunks
         if self._tuned is not None and self._tuned.l_blk:
             self.executor.spec_l_blk[0] = int(self._tuned.l_blk)  # default key
-        self._advance_fn = jax.jit(self._advance_impl)
+        self._advance_fn = named_jit(self._advance_impl, "advance_classes")
         # scan-compose dispatch counter: one per compose_lane_maps device
         # call — lets the OOO tier assert "one associative_scan per
         # contiguous run", the same way merge_calls() guards the tick path
         self.compose_calls = 0
+        # cumulative counts of _dispatch (perf_report()["dispatch"]): tile
+        # rows dispatched, the real symbols they held, and the row-steps the
+        # scan loops ran; ``calls`` numbers the public calls' root spans
+        self.dispatched = {"rows": 0, "real_symbols": 0, "run_symbols": 0}
+        self.calls = 0
         # observed-traffic accounting: every dispatched tile feeds a bounded
         # (fill, length) reservoir; maybe_retune re-runs the autotuner on a
         # probe shaped like this traffic once it drifts from what the
@@ -338,7 +344,7 @@ class Matcher:
         self.executor.retable(self.dev)
         # the jitted cursor advance baked the old tables too — fresh wrapper,
         # fresh trace cache
-        self._advance_fn = jax.jit(self._advance_impl)
+        self._advance_fn = named_jit(self._advance_impl, "advance_classes")
         return True
 
     # -- properties ---------------------------------------------------------
@@ -493,10 +499,15 @@ class Matcher:
                 else np.asarray(d, np.uint8) for d in docs]
         return arrs, np.array([a.shape[0] for a in arrs], np.int64)
 
+    def _root_span(self, entry: str, docs: int) -> TraceAnnotation:
+        """The profiler span of one public call that rides ``_dispatch``."""
+        self.calls += 1
+        return TraceAnnotation(f"repro.{entry}", docs=docs, call=self.calls)
+
     def _dispatch(self, mplan, arrs, lengths, out, *, entry_mode: str,
                   entry: Optional[np.ndarray] = None,
-                  entry_cls: Optional[np.ndarray] = None, tile_hook=None
-                  ) -> tuple[int, int, int]:
+                  entry_cls: Optional[np.ndarray] = None, tile_hook=None,
+                  finish=None) -> tuple[int, int, int, object]:
         """Run every bucket tile of a ``MatchPlan`` through the lane program.
 
         One loop serves whole documents (``ENTRY_STARTS``), resumed segments
@@ -504,10 +515,19 @@ class Matcher:
         — the planner emits the ``LanePlan``, the executor lowers it, and
         this loop only packs tiles and scatters results into ``out`` (shape
         [B, K] or [B, K, S] to match the plan's output).  Returns
-        ``(bucket_calls, padded_rows, early_exits)``.
+        ``(bucket_calls, padded_rows, early_exits, finish(out))``.
+
+        Each tile runs in three profiler spans, with kwargs ``tile`` and
+        ``width``: ``repro.pack`` (buffer, lengths, entry operands),
+        ``repro.launch`` (the executor call up to its return: lowering
+        lookup, dispatch, operand transfer) and ``repro.wait`` (one fetch of
+        the tile's outputs).  ``repro.finish`` then scatters every tile into
+        ``out`` and applies ``finish``; its kwargs carry the call's counts,
+        which also accumulate in ``self.dispatched``.
         """
         k = self.packed.n_patterns
-        calls = rows = early = 0
+        calls = rows = real = ran = 0
+        fetched = []
         for bucket in mplan.buckets:
             spec = bucket.kind == "spec"
             layout = (self.planner.layout_for(bucket.chunk_len)
@@ -521,58 +541,80 @@ class Matcher:
                                           spec_r=spec_r)
             ragged = (spec and isinstance(layout, MeshLayout)
                       and layout.is_ragged)
+            # the scan rows of one tile: documents, or document-chunks
+            tile_rows = self.batch_tile * (bucket.width // bucket.chunk_len
+                                           if spec else 1)
             for lo in range(0, bucket.doc_idx.size, self.batch_tile):
-                sel = bucket.doc_idx[lo:lo + self.batch_tile]
-                # ragged doc tiling: capacity-weighted layouts place real
-                # documents into mesh row-blocks proportionally (Eq. 7 on
-                # the doc axis) — slow rows get more zero-length pad rows.
-                # rowpos[r] is doc sel[r]'s physical tile row; results come
-                # back through the same (invertible) placement, so answers
-                # are bit-identical to the dense front-fill by construction
-                rowpos = (layout.tile_rows(sel.size, self.batch_tile)
-                          if ragged else np.arange(sel.size))
-                buf = np.zeros((self.batch_tile, bucket.width), np.uint8)
-                lens = np.zeros(self.batch_tile, np.int32)
-                for r, i in enumerate(sel):
-                    buf[rowpos[r], :lengths[i]] = arrs[i]
-                    lens[rowpos[r]] = lengths[i]
-                if tile_hook is not None:
-                    tile_hook(bucket, layout, sel, lens)
-                self.traffic.record(sel.size, lengths[sel])
-                # operands stay host numpy: jit transfers them once at call
-                # time, where an eager jnp.asarray per operand costs an extra
-                # device round-trip each on the streaming hot path
-                ent = ecls = None
-                if entry_mode == ENTRY_STATES:
-                    # pad rows scan from the pattern starts (ignored)
-                    ent = np.tile(self.packed.starts,
-                                  (self.batch_tile, 1)).astype(np.int32)
-                    ent[rowpos] = entry[sel]
-                elif entry_mode == ENTRY_LANES:
-                    # pad rows carry in-range lanes and the pad boundary key,
-                    # which the device merge composes as the identity
-                    s = self.tables.i_max
-                    ent = np.broadcast_to(
-                        self.packed.starts.astype(np.int32)[None, :, None],
-                        (self.batch_tile, k, s)).copy()
-                    ent[rowpos] = entry[sel]
-                    ecls = np.full(self.batch_tile, self.dev.pad_key,
-                                   np.int32)
-                    ecls[rowpos] = entry_cls[sel]
-                res, pos = self.executor.run(
-                    lane, buf, lens, layout=layout,
-                    entry=ent, entry_classes=ecls)
-                res, pos = np.asarray(res), np.asarray(pos)
-                out[sel] = res[rowpos]
+                tag = {"tile": calls, "width": bucket.width}
+                with TraceAnnotation("repro.pack", **tag):
+                    sel = bucket.doc_idx[lo:lo + self.batch_tile]
+                    # ragged doc tiling: capacity-weighted layouts place
+                    # real documents into mesh row-blocks proportionally
+                    # (Eq. 7 on the doc axis) — slow rows get more
+                    # zero-length pad rows.  rowpos[r] is doc sel[r]'s
+                    # physical tile row; results come back through the same
+                    # (invertible) placement, so answers are bit-identical
+                    # to the dense front-fill by construction
+                    rowpos = (layout.tile_rows(sel.size, self.batch_tile)
+                              if ragged else np.arange(sel.size))
+                    buf = np.zeros((self.batch_tile, bucket.width), np.uint8)
+                    lens = np.zeros(self.batch_tile, np.int32)
+                    for r, i in enumerate(sel):
+                        buf[rowpos[r], :lengths[i]] = arrs[i]
+                        lens[rowpos[r]] = lengths[i]
+                    if tile_hook is not None:
+                        tile_hook(bucket, layout, sel, lens)
+                    self.traffic.record(sel.size, lengths[sel])
+                    # operands stay host numpy: jit transfers them once at
+                    # call time, where an eager jnp.asarray per operand
+                    # costs an extra device round-trip each on the
+                    # streaming hot path
+                    ent = ecls = None
+                    if entry_mode == ENTRY_STATES:
+                        # pad rows scan from the pattern starts (ignored)
+                        ent = np.tile(self.packed.starts,
+                                      (self.batch_tile, 1)).astype(np.int32)
+                        ent[rowpos] = entry[sel]
+                    elif entry_mode == ENTRY_LANES:
+                        # pad rows carry in-range lanes and the pad boundary
+                        # key, which the device merge composes as the
+                        # identity
+                        s = self.tables.i_max
+                        ent = np.broadcast_to(
+                            self.packed.starts.astype(np.int32)[None, :,
+                                                                None],
+                            (self.batch_tile, k, s)).copy()
+                        ent[rowpos] = entry[sel]
+                        ecls = np.full(self.batch_tile, self.dev.pad_key,
+                                       np.int32)
+                        ecls[rowpos] = entry_cls[sel]
+                with TraceAnnotation("repro.launch", **tag):
+                    outs = self.executor.run(
+                        lane, buf, lens, layout=layout,
+                        entry=ent, entry_classes=ecls)
+                with TraceAnnotation("repro.wait", **tag):
+                    res, pos, steps = jax.device_get(outs)
                 # a doc "exited early" if all its lanes hit absorbing states
                 # before its real symbols ran out (spec positions are
                 # chunk-local, so compare against the per-chunk fill)
                 eff = (np.minimum(bucket.chunk_len, lengths[sel]) if spec
                        else lengths[sel])
-                early += int((pos[rowpos] < eff).sum())
+                fetched.append((sel, rowpos, res, pos, eff))
+                real += int(lengths[sel].sum())
+                ran += tile_rows // steps.size * int(steps.sum())
                 calls += 1
                 rows += self.batch_tile
-        return calls, rows, early
+        with TraceAnnotation("repro.finish", tiles=calls, rows=rows,
+                             real_symbols=real, run_symbols=ran):
+            early = 0
+            for sel, rowpos, res, pos, eff in fetched:
+                out[sel] = res[rowpos]
+                early += int((pos[rowpos] < eff).sum())
+            done = None if finish is None else finish(out)
+        for key, n in (("rows", rows), ("real_symbols", real),
+                       ("run_symbols", ran)):
+            self.dispatched[key] += n
+        return calls, rows, early, done
 
     def membership_batch(self, docs: Sequence[bytes | np.ndarray]) -> BatchResult:
         """Match every doc against every packed pattern; no per-doc syncs.
@@ -589,8 +631,14 @@ class Matcher:
             z = np.zeros(0, np.int64)
             return BatchResult(np.zeros((0, k), bool), np.zeros((0, k), np.int32),
                                z, z, z, 0)
-        arrs, lengths = self._as_arrays(docs)
-        plan = self.planner.plan(lengths)
+        with self._root_span("membership_batch", b):
+            return self._membership_batch(docs)
+
+    def _membership_batch(self, docs) -> BatchResult:
+        b, k = len(docs), self.packed.n_patterns
+        with TraceAnnotation("repro.plan"):
+            arrs, lengths = self._as_arrays(docs)
+            plan = self.planner.plan(lengths)
         finals = np.tile(self.packed.starts, (b, 1)).astype(np.int32)
         steps = np.where(plan.spec_mask, 0, lengths)
         device_work = (np.zeros(self.n_devices, np.int64)
@@ -614,11 +662,10 @@ class Matcher:
             if device_work is not None and isinstance(layout, MeshLayout):
                 device_work += layout.device_work(lens.astype(np.int64))
 
-        calls, _, early = self._dispatch(plan, arrs, lengths, finals,
-                                         entry_mode=ENTRY_STARTS,
-                                         tile_hook=account)
-
-        accepted = self.packed.accepting[finals]
+        calls, _, early, accepted = self._dispatch(
+            plan, arrs, lengths, finals, entry_mode=ENTRY_STARTS,
+            tile_hook=account,
+            finish=lambda out: self.packed.accepting[out])
         # lanes forces the lazy lookahead tables — only on speculative work
         lanes = k * self.tables.i_max if plan.spec_mask.any() else k
         work_par = np.where(plan.spec_mask, steps * lanes, lengths * k)
@@ -658,14 +705,15 @@ class Matcher:
         if b == 0:
             return SegmentBatchResult(entry.copy(), np.zeros((0, k), bool),
                                       np.zeros(0, np.int64), 0, 0, 0)
-        arrs, lengths = self._as_arrays(segments)
-        plan = self.planner.plan(lengths)
-        finals = entry.copy()  # zero-length segments pass through unchanged
-        calls, rows, early = self._dispatch(plan, arrs, lengths, finals,
-                                            entry_mode=ENTRY_STATES,
-                                            entry=entry)
-        return SegmentBatchResult(final_states=finals,
-                                  absorbed=self.dev.absorbing[finals],
+        with self._root_span("advance_segments", b):
+            with TraceAnnotation("repro.plan"):
+                arrs, lengths = self._as_arrays(segments)
+                plan = self.planner.plan(lengths)
+            finals = entry.copy()  # zero-length segments pass through
+            calls, rows, early, absorbed = self._dispatch(
+                plan, arrs, lengths, finals, entry_mode=ENTRY_STATES,
+                entry=entry, finish=lambda out: self.dev.absorbing[out])
+        return SegmentBatchResult(final_states=finals, absorbed=absorbed,
                                   lengths=lengths, bucket_calls=calls,
                                   padded_rows=rows, early_exits=early)
 
@@ -718,14 +766,16 @@ class Matcher:
         if b == 0:
             return CursorBatchResult(lanes.copy(), np.zeros((0, k), bool),
                                      np.zeros(0, np.int64), 0, 0, 0)
-        arrs, lengths = self._as_arrays(segments)
-        plan = self.planner.plan(lengths)
-        out = lanes.copy()  # zero-length segments compose as the identity
-        calls, rows, early = self._dispatch(plan, arrs, lengths, out,
-                                            entry_mode=ENTRY_LANES,
-                                            entry=lanes, entry_cls=last)
-        return CursorBatchResult(lane_states=out,
-                                 absorbed=self.dev.absorbing[out].all(axis=2),
+        with self._root_span("advance_cursors", b):
+            with TraceAnnotation("repro.plan"):
+                arrs, lengths = self._as_arrays(segments)
+                plan = self.planner.plan(lengths)
+            out = lanes.copy()  # zero-length segments compose as identity
+            calls, rows, early, absorbed = self._dispatch(
+                plan, arrs, lengths, out, entry_mode=ENTRY_LANES,
+                entry=lanes, entry_cls=last,
+                finish=lambda o: self.dev.absorbing[o].all(axis=2))
+        return CursorBatchResult(lane_states=out, absorbed=absorbed,
                                  lengths=lengths, bucket_calls=calls,
                                  padded_rows=rows, early_exits=early)
 
@@ -844,6 +894,13 @@ class Matcher:
             "compose_calls": self.compose_calls,
             "retunes": self.retunes,
             "traffic": None,
+            # cumulative since construction: tiles and documents (the
+            # traffic profile's counts), tile rows dispatched, the real
+            # symbols scanned, and rows x steps the scan loops ran (rows
+            # are document-chunks on spec tiles); real / run is the scan's
+            # fill
+            "dispatch": {"tiles": self.traffic.n_tiles,
+                         "docs": self.traffic.n_docs, **self.dispatched},
         }
         obs = self.traffic.snapshot()
         if obs is not None:

@@ -140,19 +140,20 @@ class ShardedExecutor(LaneExecutor):
         row_ax = tuple(doc_batch_spec(self.mesh, batch))
         buf_spec, len_spec = P(*row_ax, None), P(*row_ax)
         # the specs follow the plan's entry arity; the body is the shared one
+        # (each shard's scan loop reports its own steps: [1] per shard)
         if plan.entry == ENTRY_STARTS:
             in_specs = (buf_spec, len_spec)
-            out_specs = (buf_spec, len_spec)
+            out_specs = (buf_spec, len_spec, len_spec)
         elif plan.entry == ENTRY_LANES:
             in_specs = (buf_spec, len_spec, P(*row_ax, None, None), len_spec)
-            out_specs = (P(*row_ax, None, None), len_spec)
+            out_specs = (P(*row_ax, None, None), len_spec, len_spec)
         else:
             in_specs = (buf_spec, len_spec, P(*row_ax, None))
-            out_specs = (buf_spec, len_spec)
+            out_specs = (buf_spec, len_spec, len_spec)
         body = shard_map(lambda *args: self._seq_body(plan, *args),
                          mesh=self.mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-        return self._jit_lowering(body)
+        return self._jit_lowering(body, "seq_scan")
 
     # -- speculative plan ----------------------------------------------------
 
@@ -192,6 +193,7 @@ class ShardedExecutor(LaneExecutor):
         table_pad, cand_pad, cidx_pad = (t.table_pad_j, t.cand_pad_j,
                                          t.cidx_pad_j)
 
+        @jax.named_scope("chunk_scan")
         def scan_chunks(chunk_loc, init):
             """Per-device chunk-scan stage over this shard's lanes."""
             c_loc, b_loc = chunk_loc.shape[0], chunk_loc.shape[1]
@@ -206,6 +208,7 @@ class ShardedExecutor(LaneExecutor):
                 sym_t)
             return lvecs.reshape(c_loc, b_loc, k, s)
 
+        @jax.named_scope("merge")
         def gather_chunk_axis(lvecs, la_loc, exact_loc):
             # the only cross-device exchange, and only over "chunk": lane
             # states, not symbols; doc shards stay silent
@@ -223,11 +226,12 @@ class ShardedExecutor(LaneExecutor):
             # doc row-block, so they share one set of chunk boundaries.
             c_loc, b_loc = chunk_loc.shape[0], chunk_loc.shape[1]
             k, s = t.n_patterns, t.i_max
-            cand = cand_pad[la_loc]                    # [C_loc, B_loc, K, S]
-            start = jnp.broadcast_to(
-                entry_loc.astype(jnp.int32)[None, :, :, None],
-                (c_loc, b_loc, k, s))
-            init = jnp.where(exact_loc[:, :, None, None], start, cand)
+            with jax.named_scope("seed"):
+                cand = cand_pad[la_loc]                # [C_loc, B_loc, K, S]
+                start = jnp.broadcast_to(
+                    entry_loc.astype(jnp.int32)[None, :, :, None],
+                    (c_loc, b_loc, k, s))
+                init = jnp.where(exact_loc[:, :, None, None], start, cand)
             lv_all, la_all, ex_all = gather_chunk_axis(
                 scan_chunks(chunk_loc, init), la_loc, exact_loc)
             # every chunk device of this mesh row now folds the same gathered
@@ -242,10 +246,11 @@ class ShardedExecutor(LaneExecutor):
             # segment is matched *independently* of the prefix — and after
             # the chunk fold the caller's cursor lanes compose on device
             # (the streaming device merge).
-            cand = cand_pad[la_loc]
-            seed = jnp.broadcast_to(cand_pad[ecls_loc][None],
-                                    cand.shape)
-            init = jnp.where(exact_loc[:, :, None, None], seed, cand)
+            with jax.named_scope("seed"):
+                cand = cand_pad[la_loc]
+                seed = jnp.broadcast_to(cand_pad[ecls_loc][None],
+                                        cand.shape)
+                init = jnp.where(exact_loc[:, :, None, None], seed, cand)
             lv_all, la_all, ex_all = gather_chunk_axis(
                 scan_chunks(chunk_loc, init), la_loc, exact_loc)
             seg = self._merge_gathered(lv_all, la_all, ex_all, cidx_pad,
@@ -265,6 +270,22 @@ class ShardedExecutor(LaneExecutor):
                                  "batch_tile to a doc-shard multiple)")
             rps = b // self.doc_shards
             cls = self._classify(bytes_buf, lengths)     # [B, W]
+            chunk_buf, la, ex = chunk_operands(cls, rps)
+            if lanes_mode:
+                out = sharded_body(chunk_buf, la, ex,
+                                   entry.astype(jnp.int32), entry_cls)[0]
+            else:
+                out = sharded_body(chunk_buf, la, ex, entry)[0]
+            # each device's scan runs all lmax steps over its C * B / devices
+            # document-chunks: one loop per device
+            return (out, jnp.full((b,), NO_EXIT, jnp.int32),
+                    jnp.full((self.devices,), lmax, jnp.int32))
+
+        @jax.named_scope("seed")
+        def chunk_operands(cls, rps):
+            """[C, B, Lmax] chunk symbols, [C, B] boundary keys and exact
+            flags from the [B, W] classes."""
+            b, w = cls.shape
             # one extra identity-pad column makes column index w the "no
             # symbol here" slot — chunk tails past a boundary and the absent
             # predecessor of exact chunks both point at it
@@ -299,27 +320,22 @@ class ShardedExecutor(LaneExecutor):
                                la2 * jnp.int32(t.pad_cls) + la1)
             else:
                 la = la1  # r=1: the key *is* the class (pad_cls == pad_key)
-            ex = jnp.asarray(ex_np)                      # [C, B] bool
-            if lanes_mode:
-                out = sharded_body(chunk_buf, la, ex,
-                                   entry.astype(jnp.int32), entry_cls)[0]
-            else:
-                out = sharded_body(chunk_buf, la, ex, entry)[0]
-            return out, jnp.full((b,), NO_EXIT, jnp.int32)
+            return chunk_buf, la, jnp.asarray(ex_np)     # ex: [C, B] bool
 
         if lanes_mode:
-            return self._jit_lowering(run)
+            return self._jit_lowering(run, "spec_scan")
         if plan.entry == ENTRY_STARTS:
             def run0(bytes_buf, lengths):
                 b = bytes_buf.shape[0]
                 e = jnp.broadcast_to(t.starts_j[None, :], (b, t.n_patterns))
                 return run(bytes_buf, lengths, e, None)
 
-            return self._jit_lowering(run0)
+            return self._jit_lowering(run0, "spec_scan")
         return self._jit_lowering(
             lambda bytes_buf, lengths, entry: run(bytes_buf, lengths, entry,
-                                                  None))
+                                                  None), "spec_scan")
 
+    @jax.named_scope("merge")
     def _merge_gathered(self, lv_all: jnp.ndarray, la_all: jnp.ndarray,
                         exact_all: jnp.ndarray, cidx_pad: jnp.ndarray,
                         lanes: bool = False) -> jnp.ndarray:
