@@ -281,9 +281,11 @@ class Matcher:
         # contiguous run", the same way merge_calls() guards the tick path
         self.compose_calls = 0
         # cumulative counts of _dispatch (perf_report()["dispatch"]): tile
-        # rows dispatched, the real symbols they held, and the row-steps the
-        # scan loops ran; ``calls`` numbers the public calls' root spans
-        self.dispatched = {"rows": 0, "real_symbols": 0, "run_symbols": 0}
+        # rows dispatched, the real symbols they held, the row-steps a loop
+        # stopping at each tile's longest row would run, and the row-steps
+        # the scan loops ran; ``calls`` numbers the public calls' root spans
+        self.dispatched = {"rows": 0, "real_symbols": 0, "bound_symbols": 0,
+                           "run_symbols": 0}
         self.calls = 0
         # observed-traffic accounting: every dispatched tile feeds a bounded
         # (fill, length) reservoir; maybe_retune re-runs the autotuner on a
@@ -516,6 +518,8 @@ class Matcher:
         this loop only packs tiles and scatters results into ``out`` (shape
         [B, K] or [B, K, S] to match the plan's output).  Returns
         ``(bucket_calls, padded_rows, early_exits, finish(out))``.
+        Tiles follow ``bucket.doc_idx``, which the planner emits longest
+        document first, and ``sel`` carries every result back.
 
         Each tile runs in three profiler spans, with kwargs ``tile`` and
         ``width``: ``repro.pack`` (buffer, lengths, entry operands),
@@ -526,7 +530,7 @@ class Matcher:
         which also accumulate in ``self.dispatched``.
         """
         k = self.packed.n_patterns
-        calls = rows = real = ran = 0
+        calls = rows = real = bound = ran = 0
         fetched = []
         for bucket in mplan.buckets:
             spec = bucket.kind == "spec"
@@ -601,18 +605,23 @@ class Matcher:
                        else lengths[sel])
                 fetched.append((sel, rowpos, res, pos, eff))
                 real += int(lengths[sel].sum())
+                # every row run to the tile's longest effective row: what
+                # one loop stopping exactly there runs (an absorbing exit,
+                # or a mesh's per-shard loops, can run less)
+                bound += tile_rows * int(eff.max())
                 ran += tile_rows // steps.size * int(steps.sum())
                 calls += 1
                 rows += self.batch_tile
         with TraceAnnotation("repro.finish", tiles=calls, rows=rows,
-                             real_symbols=real, run_symbols=ran):
+                             real_symbols=real, bound_symbols=bound,
+                             run_symbols=ran):
             early = 0
             for sel, rowpos, res, pos, eff in fetched:
                 out[sel] = res[rowpos]
                 early += int((pos[rowpos] < eff).sum())
             done = None if finish is None else finish(out)
         for key, n in (("rows", rows), ("real_symbols", real),
-                       ("run_symbols", ran)):
+                       ("bound_symbols", bound), ("run_symbols", ran)):
             self.dispatched[key] += n
         return calls, rows, early, done
 
@@ -896,9 +905,11 @@ class Matcher:
             "traffic": None,
             # cumulative since construction: tiles and documents (the
             # traffic profile's counts), tile rows dispatched, the real
-            # symbols scanned, and rows x steps the scan loops ran (rows
-            # are document-chunks on spec tiles); real / run is the scan's
-            # fill
+            # symbols scanned, rows x each tile's longest effective row,
+            # and rows x steps the scan loops ran (rows are
+            # document-chunks on spec tiles); real / run is the scan's
+            # fill, the product of packing (real / bound) and segment
+            # rounding (bound / run)
             "dispatch": {"tiles": self.traffic.n_tiles,
                          "docs": self.traffic.n_docs, **self.dispatched},
         }

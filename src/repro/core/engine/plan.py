@@ -421,7 +421,7 @@ class BucketPlan:
     kind: str            # "seq" | "spec"
     width: int           # padded byte/symbol width of the device buffer
     chunk_len: int       # Lc for spec buckets (width == C * Lc); 0 for seq
-    doc_idx: np.ndarray  # [n_docs] int64 indices into the batch
+    doc_idx: np.ndarray  # [n_docs] int64 batch indices, in tile order
 
 
 @dataclasses.dataclass(frozen=True)
@@ -488,6 +488,11 @@ class MatchPlan:
     @property
     def n_docs(self) -> int:
         return int(self.lengths.shape[0])
+
+
+def _tile_order(idx: np.ndarray, lengths: np.ndarray) -> np.ndarray:
+    """``idx`` longest document first; a stable sort keeps ties in order."""
+    return idx[np.argsort(-lengths[idx], kind="stable")]
 
 
 class Planner:
@@ -633,7 +638,14 @@ class Planner:
     # -- batch planning -----------------------------------------------------
 
     def plan(self, lengths: np.ndarray) -> MatchPlan:
-        """Assign every document to a bucket, updating the sticky key set."""
+        """Assign every document to a bucket, updating the sticky key set.
+
+        Each bucket's ``doc_idx`` is in tile order: longest document first,
+        ties in arrival order.  The dispatch loop cuts it into consecutive
+        tiles, and a tile's scan stops only once its longest row is done,
+        so grouping similar lengths lets each tile stop near its own
+        longest row; results return to their documents through ``doc_idx``.
+        """
         lengths = np.asarray(lengths, dtype=np.int64)
         b = lengths.shape[0]
         c = self.num_chunks
@@ -641,7 +653,7 @@ class Planner:
         chunk_len = np.zeros(b, np.int64)
         buckets: list[BucketPlan] = []
 
-        seq_idx = np.flatnonzero(~spec)
+        seq_idx = _tile_order(np.flatnonzero(~spec), lengths)
         if seq_idx.size and int(lengths[seq_idx].max()) > 0:
             lmax = int(lengths[seq_idx].max())
             if lmax > self.seq_width:  # only reachable when num_chunks <= 1
@@ -667,7 +679,7 @@ class Planner:
                 fresh.pop(0)
             self.spec_keys = sorted(set(known) | set(fresh))
             for key in sorted(set(lc.tolist())):
-                sel = spec_idx[lc == key]
+                sel = _tile_order(spec_idx[lc == key], lengths)
                 chunk_len[sel] = key
                 buckets.append(BucketPlan("spec", c * key, key, sel))
 

@@ -1,10 +1,11 @@
 """The matcher's dispatch counters (``Matcher.perf_report()["dispatch"]``).
 
 ``run_symbols`` is rows x the symbol steps each scan loop ran, summed over
-loops, and ``real_symbols`` the real bytes scanned.  Each case works out the
-loop's steps on the host from the documents (where each stops being
-undecided) and the early-exit segments, and checks the counters against
-them: on the local backend (seq and spec plans, with and without the early
+loops, ``real_symbols`` the real bytes scanned, and ``bound_symbols`` rows x
+each tile's longest effective row (the longest document on seq tiles, its
+share of one chunk on spec tiles).  Each case works out the loop's steps on
+the host from the documents (where each stops being undecided) and the
+early-exit segments, and checks the counters against them: on the local backend (seq and spec plans, with and without the early
 exit, and the Pallas kernel's skipped blocks) and on the sharded backend
 over 4 devices, where every shard runs its own loop.
 """
@@ -67,6 +68,7 @@ def test_local_seq_counts(docs, segs):
     width = 1024  # the sticky seq width of documents up to 1,000 bytes
     assert d["tiles"] == 1 and d["docs"] == 4 and d["rows"] == 4
     assert d["real_symbols"] == sum(map(len, docs))
+    assert d["bound_symbols"] == 4 * max(map(len, docs))
     assert d["run_symbols"] == 4 * loop_steps(docs, width, segs)
 
 
@@ -81,6 +83,8 @@ def test_local_spec_counts(docs, chunk_steps):
     # rows are document-chunks: the tile's 4 rows x 4 chunks in one loop
     assert d["rows"] == 4
     assert d["real_symbols"] == sum(map(len, docs))
+    # each document fills its 256-symbol chunks
+    assert d["bound_symbols"] == 4 * 4 * 256
     assert d["run_symbols"] == 4 * 4 * chunk_steps
 
 
@@ -103,19 +107,24 @@ def _sharded(**kw):
 
 def test_sharded_seq_counts_per_shard():
     m = _sharded(batch_tile=8)
-    # seq rows split 2 per device, each device's loop stopping on its own
-    docs = [b"ab" + _x(10), b"ab" + _x(3),    # shard 0: both absorb by 4
-            _x(13), b"ab",                    # shard 1: 13 symbols -> 16
-            _x(3), _x(2),                     # shard 2: 4
-            _x(9), b"ab" + _x(13)]            # shard 3: 12
+    # seq rows split 2 per device in the planner's order, longest first,
+    # each device's loop stopping on its own
+    docs = [b"ab" + _x(10), b"ab" + _x(3), _x(13), b"ab",
+            _x(3), _x(2), _x(9), b"ab" + _x(13)]
+    order = m.planner.plan(np.array([len(d) for d in docs])).buckets[0].doc_idx
+    np.testing.assert_array_equal(order, [7, 2, 0, 6, 1, 4, 3, 5])
+    rows = [docs[i] for i in order]
     d = _delta(m, docs)
     assert "seq-sharded" in m.perf_report()["lowerings"].values()
     width, segs = 16, 4  # the seq width of a 4-chunk planner
-    want = sum(2 * loop_steps(docs[i:i + 2], width, segs)
+    want = sum(2 * loop_steps(rows[i:i + 2], width, segs)
                for i in range(0, 8, 2))
-    assert want == 2 * (4 + 16 + 4 + 12)
+    # shard 0: 15 and 13 symbols -> 16; shard 1: 12 (absorbed by 4) and 9
+    # -> 12; shard 2: 5 (absorbed) and 3 -> 4; shard 3: 2 and 2 -> 4
+    assert want == 2 * (16 + 12 + 4 + 4)
     assert d["run_symbols"] == want
     assert d["real_symbols"] == sum(map(len, docs))
+    assert d["bound_symbols"] == 8 * 15
 
 
 def test_sharded_spec_counts_every_device_loop():

@@ -175,3 +175,38 @@ if HAVE_HYPOTHESIS:
         rng = np.random.default_rng(seed)
         w = capacity_weights(rng.uniform(0.25, 4.0, size=p))
         _check_is_partition(weighted_partition(n, w, m), n)
+
+
+def _tile_max_sum(lengths, order, tile):
+    return sum(int(lengths[order[lo:lo + tile]].max())
+               for lo in range(0, order.size, tile))
+
+
+@pytest.mark.parametrize("lengths,num_chunks", [
+    pytest.param(np.random.default_rng(7).integers(0, 2000, 300), 1,
+                 id="ragged-seq"),
+    pytest.param(np.random.default_rng(8).integers(0, 3000, 300), 8,
+                 id="ragged-seq-and-spec"),
+    pytest.param(np.array([5, 9, 5, 20, 9, 9, 1, 20, 5]), 1, id="ties"),
+    pytest.param(np.array([40, 100, 40, 100, 900, 40, 3, 3, 900]), 4,
+                 id="ties-spec"),
+    pytest.param(np.full(150, 333), 1, id="equal-seq"),
+    pytest.param(np.full(150, 333), 8, id="equal-spec"),
+])
+def test_planner_emits_buckets_in_tile_order(lengths, num_chunks):
+    plan = Planner(num_chunks=num_chunks).plan(lengths)
+    covered = np.concatenate([b.doc_idx for b in plan.buckets])
+    assert sorted(covered.tolist()) == list(range(lengths.size))
+    for b in plan.buckets:
+        arrival = np.sort(b.doc_idx)
+        # a permutation of the bucket's documents, longest first, ties in
+        # arrival order
+        want = arrival[np.lexsort((arrival, -lengths[arrival]))]
+        np.testing.assert_array_equal(b.doc_idx, want)
+        assert (np.diff(lengths[b.doc_idx]) <= 0).all()
+        if np.unique(lengths[b.doc_idx]).size == 1:
+            np.testing.assert_array_equal(b.doc_idx, arrival)
+        # grouping sorted lengths never raises the tiles' longest rows
+        for tile in (1, 4, 64):
+            assert (_tile_max_sum(lengths, b.doc_idx, tile)
+                    <= _tile_max_sum(lengths, arrival, tile))

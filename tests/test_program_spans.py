@@ -70,10 +70,12 @@ def test_call_writes_root_plan_and_tile_spans(traced):
     finish = next(s[3] for s in inner if s[0] == "repro.finish")
     assert finish["tiles"] == 2 and finish["rows"] == 8
     assert finish["real_symbols"] == sum(map(len, docs))
+    # tiles longest first: (500, 300, 64, 40) and (17, 3, 0)
+    assert finish["bound_symbols"] == 4 * 500 + 4 * 17
     # finish's counts are the ones perf_report accumulates (two calls)
     rep = m.perf_report()["dispatch"]
-    assert rep["run_symbols"] == 2 * finish["run_symbols"]
-    assert rep["real_symbols"] == 2 * finish["real_symbols"]
+    for key in ("real_symbols", "bound_symbols", "run_symbols"):
+        assert rep[key] == 2 * finish[key]
 
 
 def test_spans_follow_each_other_per_tile(traced):
