@@ -46,8 +46,9 @@ Backends (the three lowerings):
 
   * ``LocalExecutor``                  — pure-jnp jitted lowering (the
     oracle), with an absorbing-state early exit: the symbol scan runs in
-    segments inside a ``lax.while_loop`` and stops once every lane of every
-    document is absorbing.
+    segments inside a ``lax.while_loop``, checks after each segment, and
+    stops once every lane of every document is absorbing.  It never runs
+    past the tile's longest row: the segment that reaches it stops there.
   * ``LocalExecutor(use_kernel=True)`` — the fused Pallas kernels
     (``kernels.ops.spec_match_merge`` for exact-entry plans,
     ``kernels.ops.spec_match_merge_lanes`` for ``ENTRY_LANES`` — the
@@ -293,55 +294,58 @@ class LaneExecutor:
                          eff_len: jnp.ndarray, scan_len: int,
                          early_exit: bool = True
                          ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-        """Scan ``states [R, S]`` through ``sym_t [L, R]`` symbol columns in
-        segments, stopping once every document is *done*: all its lanes are
-        absorbing, or the scan has passed its real symbols (``eff_len [B]``
-        per-doc; pure-padding rows of a partial tile are done immediately,
-        so they never pin the loop to the full scan).
+        """Scan ``states [R, S]`` through ``sym_t [L, R]`` symbol columns up
+        to the longest document's effective length ``max(eff_len)`` (``eff_len
+        [B]`` per doc), checking for the absorbing exit once per segment of
+        ``scan_len // segs`` steps: the loop stops at the first check at
+        which every document is *done* — all its lanes are absorbing, or the
+        scan has passed its real symbols — and the segment that reaches the
+        longest document's end runs only up to it.  Pure-padding rows of a
+        partial tile are done immediately, so they never pin the loop.
 
         Rows are doc-major (R = B * rows_per_doc).  Returns (final states,
-        absorbed_pos [B], steps [1]) with ``absorbed_pos`` the first segment
-        boundary at which a document's lanes were all absorbing (sentinel
-        ``NO_EXIT`` otherwise) and ``steps`` the symbol steps the loop ran
-        over all R rows.  Exactness: absorbing states self-loop on every
-        class and padding is the identity column, so skipping the remaining
-        symbols of a done document is bit-identical.
+        absorbed_pos [B], steps [1]) with ``absorbed_pos`` the first check
+        position (a segment boundary, or the longest document's end) at
+        which a document's lanes were all absorbing (sentinel ``NO_EXIT``
+        otherwise) and ``steps`` the symbol steps the loop ran over all R
+        rows.  Exactness: absorbing states self-loop on every class and
+        padding is the identity column, so skipping the remaining symbols of
+        a done document, and every column past the longest one, is
+        bit-identical.
         """
         table = self.t.table_pad_j
         absorbing = self.t.absorbing_j
         b = eff_len.shape[0]
+        eff = eff_len.astype(jnp.int32)
+        bound = jnp.minimum(jnp.max(eff, initial=0), scan_len)
 
-        def seg_scan(st, cols):
-            def step(s, row):
-                return table[s, row[:, None]], None
-            out, _ = jax.lax.scan(step, st, cols)
-            return out
+        def scan_range(st, lo, hi):
+            # traced bounds: one compiled program serves every tile's bound
+            def step(i, s):
+                return table[s, sym_t[i][:, None]]
+            return jax.lax.fori_loop(lo, hi, step, st)
 
         segs = min(self.early_exit_segments if early_exit else 1, scan_len)
         pos0 = jnp.full((b,), NO_EXIT, jnp.int32)
-        if segs <= 1 or scan_len == 0:
-            return (seg_scan(states, sym_t), pos0,
-                    jnp.full((1,), scan_len, jnp.int32))
+        if segs <= 1:
+            return scan_range(states, 0, bound), pos0, bound[None]
         seg_len = scan_len // segs
 
         def cond(carry):
-            _, g, _, all_done = carry
-            return (g < segs) & ~all_done
+            _, lo, _, all_done = carry
+            return (lo < bound) & ~all_done
 
         def body(carry):
-            st, g, pos, _ = carry
-            cols = jax.lax.dynamic_slice_in_dim(sym_t, g * seg_len, seg_len,
-                                                axis=0)
-            st = seg_scan(st, cols)
+            st, lo, pos, _ = carry
+            hi = jnp.minimum(lo + seg_len, bound)
+            st = scan_range(st, lo, hi)
             doc_abs = absorbing[st].reshape(b, -1).all(axis=1)
-            boundary = ((g + 1) * seg_len).astype(jnp.int32)
-            pos = jnp.where(doc_abs & (pos == NO_EXIT), boundary, pos)
-            done = doc_abs | (boundary >= eff_len.astype(jnp.int32))
-            return st, g + 1, pos, done.all()
+            pos = jnp.where(doc_abs & (pos == NO_EXIT), hi, pos)
+            return st, hi, pos, (doc_abs | (hi >= eff)).all()
 
-        states, g, pos, _ = jax.lax.while_loop(
+        states, ran, pos, _ = jax.lax.while_loop(
             cond, body, (states, jnp.int32(0), pos0, jnp.bool_(False)))
-        return states, pos, (g * seg_len).astype(jnp.int32)[None]
+        return states, pos, ran[None]
 
     # -- stage: device cursor merge (lane plans) -----------------------------
 

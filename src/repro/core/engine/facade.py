@@ -157,8 +157,10 @@ class Matcher:
                    ``devices=``, the paper's Sec. 4.1 step 1 run at cluster
                    start) and feed the measurements into the
                    capacity-weighted chunk layout automatically.
-    early_exit_segments : absorbing-state early-exit granularity per scan
-                   (1 disables; pow2, local/seq paths only).
+    early_exit_segments : how often a scan loop checks for the absorbing
+                   early exit: once per 1/n of the scan width (1 disables;
+                   pow2, local/seq paths only).  The loop never scans past
+                   its tile's longest row, whatever n.
     lookahead_r  : boundary-key lookahead depth of the candidate tables:
                    1 (the paper's Eq. 11 last-byte class), 2 (Eq. 13 pair
                    keys — smaller feasible candidate sets shrink the lane
